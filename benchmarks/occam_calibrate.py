@@ -26,8 +26,6 @@ from __future__ import annotations
 
 import json
 import os
-import subprocess
-import sys
 import time
 
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -211,21 +209,9 @@ def occam_calibrate():
     """Harness entry (``benchmarks.run``): spawn the flagged subprocess
     and report the prediction-error improvement factor of the calibrated
     cost model over the analytic roofline."""
-    from benchmarks.occam_stap import _merged_flags
+    from benchmarks.emulated import run_child
 
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = _merged_flags(env.get("XLA_FLAGS", "")) \
-        or env.get("XLA_FLAGS", "")
-    env["PYTHONPATH"] = os.path.join(_ROOT, "src") + (
-        os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
-    res = subprocess.run([sys.executable, "-m",
-                          "benchmarks.occam_calibrate"],
-                         cwd=_ROOT, env=env, capture_output=True, text=True)
-    if res.returncode != 0:
-        raise RuntimeError(f"occam_calibrate subprocess failed:\n"
-                           f"{res.stderr[-2000:]}")
-    with open(_OUT) as f:
-        row = json.load(f)
+    row = run_child("benchmarks.occam_calibrate", _OUT)
     validate_doc(row)
     return [row], row["error_improvement"]
 
@@ -240,12 +226,6 @@ def main() -> None:
 
 
 if __name__ == "__main__":
-    from benchmarks.occam_stap import _merged_flags
+    from benchmarks.emulated import launch
 
-    _flags = _merged_flags(os.environ.get("XLA_FLAGS", ""))
-    if _flags is not None:
-        env = dict(os.environ, XLA_FLAGS=_flags)
-        sys.exit(subprocess.run([sys.executable, "-m",
-                                 "benchmarks.occam_calibrate"],
-                                cwd=_ROOT, env=env).returncode)
-    main()
+    launch("benchmarks.occam_calibrate", main)

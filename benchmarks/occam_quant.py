@@ -26,8 +26,6 @@ from __future__ import annotations
 
 import json
 import os
-import subprocess
-import sys
 
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _OUT = os.path.join(_ROOT, "results", "BENCH_quant.json")
@@ -188,20 +186,9 @@ def quant_measurement() -> dict:
 def occam_quant():
     """Harness entry (``benchmarks.run``): spawn the flagged subprocess
     and report the int8-over-fp32 off-chip byte reduction."""
-    from benchmarks.occam_stap import _merged_flags
+    from benchmarks.emulated import run_child
 
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = _merged_flags(env.get("XLA_FLAGS", "")) \
-        or env.get("XLA_FLAGS", "")
-    env["PYTHONPATH"] = os.path.join(_ROOT, "src") + (
-        os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
-    res = subprocess.run([sys.executable, "-m", "benchmarks.occam_quant"],
-                         cwd=_ROOT, env=env, capture_output=True, text=True)
-    if res.returncode != 0:
-        raise RuntimeError(f"occam_quant subprocess failed:\n"
-                           f"{res.stderr[-2000:]}")
-    with open(_OUT) as f:
-        row = json.load(f)
+    row = run_child("benchmarks.occam_quant", _OUT)
     validate_doc(row)
     return [row], row["bytes_reduction_int8"]
 
@@ -216,12 +203,6 @@ def main() -> None:
 
 
 if __name__ == "__main__":
-    from benchmarks.occam_stap import _merged_flags
+    from benchmarks.emulated import launch
 
-    _flags = _merged_flags(os.environ.get("XLA_FLAGS", ""))
-    if _flags is not None:
-        env = dict(os.environ, XLA_FLAGS=_flags)
-        sys.exit(subprocess.run([sys.executable, "-m",
-                                 "benchmarks.occam_quant"],
-                                cwd=_ROOT, env=env).returncode)
-    main()
+    launch("benchmarks.occam_quant", main)

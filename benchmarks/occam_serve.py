@@ -21,8 +21,6 @@ from __future__ import annotations
 import json
 import os
 import statistics
-import subprocess
-import sys
 import time
 
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -35,20 +33,9 @@ REPS = 3
 def occam_serve():
     """Harness entry (`benchmarks.run`): spawn the flagged subprocess and
     report measured/predicted steady serving throughput (1.0 = exact)."""
-    from benchmarks.occam_stap import _merged_flags
+    from benchmarks.emulated import run_child
 
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = _merged_flags(env.get("XLA_FLAGS", "")) \
-        or env.get("XLA_FLAGS", "")
-    env["PYTHONPATH"] = os.path.join(_ROOT, "src") + (
-        os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
-    res = subprocess.run([sys.executable, "-m", "benchmarks.occam_serve"],
-                         cwd=_ROOT, env=env, capture_output=True, text=True)
-    if res.returncode != 0:
-        raise RuntimeError(f"occam_serve subprocess failed:\n"
-                           f"{res.stderr[-2000:]}")
-    with open(_OUT) as f:
-        row = json.load(f)
+    row = run_child("benchmarks.occam_serve", _OUT)
     return [row], row["serve_thr_measured_over_predicted"]
 
 
@@ -159,14 +146,6 @@ def main() -> None:
 
 
 if __name__ == "__main__":
-    from benchmarks.occam_stap import _merged_flags
+    from benchmarks.emulated import launch
 
-    _flags = _merged_flags(os.environ.get("XLA_FLAGS", ""))
-    if _flags is not None:
-        # re-exec with the missing flags merged in (they must be set
-        # before the first jax import to take effect)
-        env = dict(os.environ, XLA_FLAGS=_flags)
-        sys.exit(subprocess.run([sys.executable, "-m",
-                                 "benchmarks.occam_serve"],
-                                cwd=_ROOT, env=env).returncode)
-    main()
+    launch("benchmarks.occam_serve", main)

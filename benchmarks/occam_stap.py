@@ -23,8 +23,9 @@ lock-step schedule prediction under the deployed times — validating the
 *runtime schedule*, with the host's parallelism measured rather than
 assumed.
 
-Writes machine-readable results to ``results/BENCH_stap.json``. Re-executes
-itself in a subprocess with the emulated-device flags when needed:
+Writes machine-readable results to ``results/BENCH_stap.json``. On the
+CPU backend it re-executes itself with the emulated-device flags
+(``benchmarks.emulated``):
 
     PYTHONPATH=src python -m benchmarks.occam_stap        # direct
     PYTHONPATH=src python -m benchmarks.run               # via harness
@@ -34,21 +35,13 @@ from __future__ import annotations
 import json
 import os
 import statistics
-import subprocess
-import sys
 import time
+
+from benchmarks.emulated import N_DEVICES
 
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _OUT = os.path.join(_ROOT, "results", "BENCH_stap.json")
 
-# single-threaded Eigen: one emulated device == one compute thread, so a
-# replicated stage's chips map onto distinct host cores (the multi-threaded
-# pool lets one stage body hog every core, serializing the replicas and
-# hiding the STAP effect)
-_XLA_FLAGS = ("--xla_force_host_platform_device_count={n} "
-              "--xla_cpu_multi_thread_eigen=false")
-
-N_DEVICES = 8
 HW = 64            # input resolution
 MICROBATCH = 1     # images per pipeline slot
 BATCH = 16         # images per stream() call
@@ -56,52 +49,12 @@ CAPACITY = 170_000  # elems: cuts the net below into [light, heavy, light]
 REPS = 5
 
 
-_COUNT_FLAG = "--xla_force_host_platform_device_count"
-_EIGEN_FLAG = "--xla_cpu_multi_thread_eigen"
-
-
-def _merged_flags(existing: str) -> str | None:
-    """XLA_FLAGS this benchmark needs, merged into ``existing`` (both
-    matter: the device count emulates the mesh, single-threaded Eigen
-    keeps one body from hogging every core and hiding the STAP effect).
-    A pre-set but too-small device count is raised to N_DEVICES — unlike
-    tests/conftest.py, which never overrides a user flag and lets tests
-    skip instead, a benchmark subprocess owns its env. Returns None when
-    ``existing`` is already sufficient."""
-    parts = existing.split()
-    have = None
-    for f in parts:
-        if f.startswith(_COUNT_FLAG + "="):
-            try:
-                have = int(f.split("=", 1)[1])
-            except ValueError:
-                have = None
-    changed = False
-    if have is None or have < N_DEVICES:
-        parts = [f for f in parts if not f.startswith(_COUNT_FLAG)]
-        parts.append(f"{_COUNT_FLAG}={N_DEVICES}")
-        changed = True
-    if not any(f.startswith(_EIGEN_FLAG) for f in parts):
-        parts.append(f"{_EIGEN_FLAG}=false")
-        changed = True
-    return " ".join(parts) if changed else None
-
-
 def occam_stap():
     """Harness entry (`benchmarks.run`): spawn the flagged subprocess and
     report the measured/predicted STAP throughput ratio (1.0 = exact)."""
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = _merged_flags(env.get("XLA_FLAGS", "")) \
-        or env.get("XLA_FLAGS", "")
-    env["PYTHONPATH"] = os.path.join(_ROOT, "src") + (
-        os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
-    res = subprocess.run([sys.executable, "-m", "benchmarks.occam_stap"],
-                         cwd=_ROOT, env=env, capture_output=True, text=True)
-    if res.returncode != 0:
-        raise RuntimeError(f"occam_stap subprocess failed:\n"
-                           f"{res.stderr[-2000:]}")
-    with open(_OUT) as f:
-        row = json.load(f)
+    from benchmarks.emulated import run_child
+
+    row = run_child("benchmarks.occam_stap", _OUT)
     return [row], row["stap_thr_measured_over_predicted"]
 
 
@@ -134,8 +87,6 @@ def stage_timers(pipe, params, replicas=None):
     import jax.numpy as jnp
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from repro.models.sharding import shard_map_compat
-
     pstack = pipe._stack_params(params)
     fns = []
     for k, st in enumerate(pipe.stages):
@@ -147,7 +98,7 @@ def stage_timers(pipe, params, replicas=None):
             fns.append(lambda fn=fn, p=pstack[k], s=slot: fn(p, s))
         else:
             mesh = Mesh(np.array(jax.devices()[:r]), ("rep",))
-            grp = jax.jit(shard_map_compat(
+            grp = jax.jit(jax.shard_map(
                 lambda p, s, body=body: body(p, s[0])[None], mesh=mesh,
                 in_specs=(P(), P("rep")), out_specs=P("rep"),
                 check_vma=False))
@@ -320,12 +271,6 @@ def main() -> None:
 
 
 if __name__ == "__main__":
-    _flags = _merged_flags(os.environ.get("XLA_FLAGS", ""))
-    if _flags is not None:
-        # re-exec with the missing flags merged in (they must be set
-        # before the first jax import to take effect)
-        env = dict(os.environ, XLA_FLAGS=_flags)
-        sys.exit(subprocess.run([sys.executable, "-m",
-                                 "benchmarks.occam_stap"],
-                                cwd=_ROOT, env=env).returncode)
-    main()
+    from benchmarks.emulated import launch
+
+    launch("benchmarks.occam_stap", main)

@@ -14,13 +14,8 @@ flags so the pipeline/serving families get a mesh, exactly as
 """
 from __future__ import annotations
 
-import os
-import subprocess
-import sys
 import tempfile
 import time
-
-_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _tiny_case():
@@ -165,11 +160,6 @@ def main() -> None:
 
 
 if __name__ == "__main__":
-    from benchmarks.occam_stap import _merged_flags
+    from benchmarks.emulated import launch
 
-    _flags = _merged_flags(os.environ.get("XLA_FLAGS", ""))
-    if _flags is not None:
-        env = dict(os.environ, XLA_FLAGS=_flags)
-        sys.exit(subprocess.run([sys.executable, "-m", "benchmarks.smoke"],
-                                cwd=_ROOT, env=env).returncode)
-    main()
+    launch("benchmarks.smoke", main)

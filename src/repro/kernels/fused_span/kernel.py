@@ -41,16 +41,38 @@ output block out per step.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 from jax.experimental import pallas as pl
 
 from repro.core import closure
 from repro.core.graph import NetSpec
 
 from .rowops import NEG_INF, conv_row, pool_row, project_row, ring_window
+
+
+# (net name, a, b, interpret) of every span kernel this process has
+# lowered, appended at trace time: evidence of which mode really ran.
+LOWERINGS: list[tuple[str, int, int, bool]] = []
+
+# Left over the kernel's own buffers for the values its body keeps live
+# (windows, column phases, accumulators): the compiler's default scoped
+# VMEM budget, which every kernel got before it asked for a limit.
+_VMEM_HEADROOM_BYTES = 16 * 1024 * 1024
+
+
+def _tiled_bytes(shape: tuple[int, ...], itemsize: int) -> int:
+    """VMEM bytes of a buffer under the TPU's tiled layout: the two minor
+    dims pad to (sublanes, 128 lanes) tiles, 8 sublanes of 32 bits. A
+    3-channel row therefore costs as much as a 128-channel one."""
+    sub = 8 * max(1, 4 // itemsize)
+    *lead, rows, cols = (1,) * max(0, 2 - len(shape)) + tuple(shape)
+    return (math.prod(lead) * (-(-rows // sub) * sub)
+            * (-(-cols // 128) * 128) * itemsize)
 
 
 def _span_kernel(sched_ref, outrow_ref, inrow_ref, x_ref, *refs,
@@ -81,6 +103,8 @@ def _span_kernel(sched_ref, outrow_ref, inrow_ref, x_ref, *refs,
             rings[0][(g % caps[0]).astype(jnp.int32)] = x_ref[0, ii]
 
     # --- scheduled production: maps a+1 .. b in dependency order ----------
+    # A map's slots run as one loop over its slot-table columns, so the
+    # body is emitted once per map rather than once per slot.
     slot = 0
     wb_idx = 0
     for off in range(1, n_maps):
@@ -92,13 +116,13 @@ def _span_kernel(sched_ref, outrow_ref, inrow_ref, x_ref, *refs,
             wb_idx += 2
         else:
             w_ref = b_ref = None
-        for _ in range(schedule.slots[off - 1]):
-            r = sched_ref[i, slot]
-            slot += 1
+
+        def produce(j, carry, slot0=slot, off=off, m=m, layer=layer,
+                    w_m=w_m, c_m=c_m, w_ref=w_ref, b_ref=b_ref):
+            r = sched_ref[i, slot0 + j]
 
             @pl.when(r >= 0)
-            def _produce(r=r, off=off, m=m, layer=layer, w_m=w_m, c_m=c_m,
-                         w_ref=w_ref, b_ref=b_ref):
+            def _produce():
                 pad_val = 0.0 if layer.kind == "conv" else NEG_INF
                 win = ring_window(rings[off - 1], r, layer.k, layer.stride,
                                   layer.padding, h[off - 1], caps[off - 1],
@@ -134,6 +158,15 @@ def _span_kernel(sched_ref, outrow_ref, inrow_ref, x_ref, *refs,
                     sref = spill_refs[spill.index(m)]
                     sref[0, r] = row.astype(sref.dtype)
 
+            return carry
+
+        n_slots = schedule.slots[off - 1]
+        if n_slots == 1:
+            produce(0, 0)
+        else:
+            lax.fori_loop(0, n_slots, produce, 0)
+        slot += n_slots
+
 
 @functools.partial(jax.jit,
                    static_argnames=("net", "a", "b", "schedule", "spill",
@@ -145,6 +178,7 @@ def _span_pallas(xs: jax.Array, wb: tuple[jax.Array, ...],
                  interpret: bool):
     from jax.experimental.pallas import tpu as pltpu
 
+    LOWERINGS.append((net.name, a, b, interpret))
     batch = xs.shape[0]
     n_maps = b - a + 1
     h_b, w_b, c_b = net.map_shape(b)
@@ -173,10 +207,12 @@ def _span_pallas(xs: jax.Array, wb: tuple[jax.Array, ...],
             (1,) + net.map_shape(s),
             lambda n, i, ss, o, ir: (n, 0, 0, 0)))
     # chip-resident filters: whole arrays, constant index map -> fetched
-    # once, shared across the whole batch grid dimension (Eqn. 6)
+    # once, shared across the whole batch grid dimension (Eqn. 6). One
+    # buffer each: a block that is never re-fetched needs no second one.
     for arr in wb:
         in_specs.append(pl.BlockSpec(
-            arr.shape, lambda n, i, s, o, ir, nd=arr.ndim: (0,) * nd))
+            arr.shape, lambda n, i, s, o, ir, nd=arr.ndim: (0,) * nd,
+            pipeline_mode=pl.Buffered(1)))
 
     out_specs = [
         # out_rows-row output block per producing step (Eqn. 6 tile)
@@ -193,17 +229,23 @@ def _span_pallas(xs: jax.Array, wb: tuple[jax.Array, ...],
         out_shapes.append(
             jax.ShapeDtypeStruct((batch,) + net.map_shape(m), xs.dtype))
 
+    ring_shapes = [(schedule.ring_caps[off],) + net.map_shape(a + off)[1:]
+                   for off in range(n_maps - 1)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(batch, schedule.n_steps),
         in_specs=in_specs,
         out_specs=out_specs,
-        scratch_shapes=[
-            pltpu.VMEM((schedule.ring_caps[off],) + net.map_shape(a + off)[1:],
-                       xs.dtype)
-            for off in range(n_maps - 1)
-        ],
+        scratch_shapes=[pltpu.VMEM(shape, xs.dtype) for shape in ring_shapes],
     )
+    itemsize = jnp.dtype(xs.dtype).itemsize
+    streamed = [spec.block_shape for spec in in_specs[:1 + len(srcs)]]
+    streamed += [spec.block_shape for spec in out_specs]
+    vmem_bytes = (
+        2 * sum(_tiled_bytes(shape, itemsize) for shape in streamed)
+        + sum(_tiled_bytes(arr.shape, jnp.dtype(arr.dtype).itemsize)
+              for arr in wb)
+        + sum(_tiled_bytes(shape, itemsize) for shape in ring_shapes))
     kernel = functools.partial(_span_kernel, net=net, a=a, b=b,
                                schedule=schedule, n_src=len(srcs),
                                n_wb=len(wb), src_keys=src_keys, spill=spill)
@@ -211,6 +253,8 @@ def _span_pallas(xs: jax.Array, wb: tuple[jax.Array, ...],
         kernel,
         grid_spec=grid_spec,
         out_shape=out_shapes,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_bytes + _VMEM_HEADROOM_BYTES),
         interpret=interpret,
     )(sched_tab, outrow_tab, inrow_tab, xs, *srcs, *wb)
     return outs[0], tuple(outs[1:])

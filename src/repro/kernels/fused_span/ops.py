@@ -23,6 +23,12 @@ from .kernel import span_kernel_vmem_elems, span_pallas_call
 from .ref import fused_span_ref
 
 
+def default_interpret() -> bool:
+    """Interpret mode everywhere but a TPU backend: on the CPU the kernel
+    body runs as Python for correctness tests; on a chip it compiles."""
+    return jax.default_backend() != "tpu"
+
+
 def span_forward(xs: jax.Array, layer_params: list[dict], net: NetSpec,
                  a: int, b: int, interpret: bool | None = None,
                  out_rows: int = 1,
@@ -58,7 +64,7 @@ def span_forward(xs: jax.Array, layer_params: list[dict], net: NetSpec,
             if w.shape != (layer.k, layer.k, layer.in_ch, layer.out_ch):
                 raise ValueError(f"layer {a + off} weight shape {w.shape}")
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = default_interpret()
     ys, spilled = span_pallas_call(xs, layer_params, net, a, b,
                                    interpret=interpret, out_rows=out_rows,
                                    srcs=srcs, spill=spill)
@@ -94,5 +100,5 @@ def fused_span(x: jax.Array, w1: jax.Array, b1: jax.Array,
                         net, 0, 2, interpret=interpret)
 
 
-__all__ = ["fused_span", "fused_span_ref", "span_forward",
-           "span_kernel_vmem_elems"]
+__all__ = ["default_interpret", "fused_span", "fused_span_ref",
+           "span_forward", "span_kernel_vmem_elems"]

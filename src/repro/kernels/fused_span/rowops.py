@@ -11,13 +11,50 @@ Convs are executed as k*k MXU matmuls (W_out, C_in) @ (C_in, C_out) over
 horizontally shifted/strided row windows, accumulating in fp32
 (channels-minor layout; the MXU-friendly form of the paper's row-streamed
 convolution). Pools are k*k running maxima with -inf padding.
+
+Horizontal strides never slice a value with a step: Mosaic (the TPU
+kernel compiler) lowers neither ``x[a:b:s]`` nor a strided ``lax.slice``.
+A strided layer instead splits each window row into its ``stride`` column
+phases with one-hot selection matmuls (``column_phases``), after which
+every tap reads a contiguous slice of one phase.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 NEG_INF = -1e30
+
+
+def select_cols(row, start: int, stride: int, n: int):
+    """Columns ``start, start + stride, ...`` (``n`` of them) of a (W, C)
+    row as a one-hot (n, W) @ (W, C) matmul. ``precision=HIGHEST`` keeps
+    it exact in fp32: each output is one input times 1.0 plus zeros."""
+    shape = (n, row.shape[0])
+    sel = (lax.broadcasted_iota(jnp.int32, shape, 1)
+           == start + stride * lax.broadcasted_iota(jnp.int32, shape, 0))
+    out = jnp.dot(sel.astype(jnp.float32), row.astype(jnp.float32),
+                  precision=lax.Precision.HIGHEST,
+                  preferred_element_type=jnp.float32)
+    return out.astype(row.dtype)
+
+
+def column_phases(row, stride: int, k: int):
+    """Split a (W, C) row into the column phases a k-wide tap reads:
+    phase p holds columns p, p + stride, ... so column dx + stride * i is
+    row i of phase dx % stride, offset by dx // stride."""
+    w = row.shape[0]
+    return [select_cols(row, p, stride, -(-(w - p) // stride))
+            for p in range(min(stride, k))]
+
+
+def _tap_cols(window, dy: int, dx: int, stride: int, out_w: int, phases):
+    """Columns dx, dx + stride, ... (out_w of them) of window row dy."""
+    if stride == 1:
+        return window[dy, dx:dx + out_w, :]
+    q = dx // stride
+    return phases[dy][dx % stride][q:q + out_w, :]
 
 
 def ring_window(ring, r, k: int, stride: int, padding: int, h_prev: int,
@@ -51,10 +88,11 @@ def conv_row(window, w, b, stride: int, padding: int, out_w: int):
     if padding:
         window = jnp.pad(window, ((0, 0), (padding, padding), (0, 0)))
     acc = jnp.zeros((out_w, w.shape[-1]), jnp.float32)
-    span = stride * (out_w - 1) + 1
+    phases = [column_phases(window[dy], stride, k) for dy in range(k)] \
+        if stride > 1 else None
     for dy in range(k):
         for dx in range(k):
-            cols = window[dy, dx:dx + span:stride, :]
+            cols = _tap_cols(window, dy, dx, stride, out_w, phases)
             acc += jnp.dot(cols.astype(jnp.float32),
                            w[dy, dx].astype(jnp.float32),
                            preferred_element_type=jnp.float32)
@@ -67,11 +105,13 @@ def pool_row(window, k: int, stride: int, padding: int, out_w: int):
     if padding:
         window = jnp.pad(window, ((0, 0), (padding, padding), (0, 0)),
                          constant_values=NEG_INF)
-    span = stride * (out_w - 1) + 1
+    phases = [column_phases(window[dy], stride, k) for dy in range(k)] \
+        if stride > 1 else None
     acc = jnp.full((out_w, window.shape[-1]), NEG_INF, window.dtype)
     for dy in range(k):
         for dx in range(k):
-            acc = jnp.maximum(acc, window[dy, dx:dx + span:stride, :])
+            acc = jnp.maximum(
+                acc, _tap_cols(window, dy, dx, stride, out_w, phases))
     return acc
 
 
@@ -80,7 +120,7 @@ def project_row(src_row, w_t: int, c_t: int):
     strided horizontal subsample + channel pad/trim. src_row: (W_s, C_s)."""
     w_s, c_s = src_row.shape
     sw = max(w_s // w_t, 1)
-    y = src_row[::sw, :][:w_t, :]
+    y = select_cols(src_row, 0, sw, w_t) if sw > 1 else src_row[:w_t, :]
     if c_t > c_s:
         y = jnp.pad(y, ((0, 0), (0, c_t - c_s)))
     elif c_t < c_s:

@@ -31,8 +31,6 @@ import math
 import jax
 import jax.numpy as jnp
 
-from .sharding import shard_map_compat as _shard_map
-
 from .sharding import current_ctx, shard
 
 
@@ -159,7 +157,7 @@ def moe_sublayer(p, x: jax.Array, moe_cfg, impl: str | None = None
         z = aux[1]
         return y, lb, z
 
-    y, lb, z = _shard_map(
+    y, lb, z = jax.shard_map(
         shmap_fn, mesh=mesh,
         in_specs=(P(data_spec, None, None), P(None, None),
                   P(model, None, None), P(model, None, None),

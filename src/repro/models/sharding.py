@@ -20,25 +20,6 @@ from typing import Iterator
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # jax >= 0.5 exposes shard_map at top level; 0.4.x under experimental
-    _shard_map_impl = jax.shard_map
-except AttributeError:
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-# check_rep was renamed check_vma; the location of shard_map doesn't pin
-# which spelling a given jax accepts, so ask the signature.
-import inspect as _inspect
-
-_SHARD_MAP_CHECK_KW = ("check_vma" if "check_vma" in _inspect.signature(
-    _shard_map_impl).parameters else "check_rep")
-
-
-def shard_map_compat(f, **kw):
-    """jax-version-portable shard_map (callers use the new check_vma kw)."""
-    if "check_vma" in kw:
-        kw[_SHARD_MAP_CHECK_KW] = kw.pop("check_vma")
-    return _shard_map_impl(f, **kw)
-
 
 @dataclasses.dataclass(frozen=True)
 class ShardCtx:

@@ -144,7 +144,6 @@ def measure_hop_seconds(ring, *, iters: int = 8,
     link rate. Returns 0.0 for single-stage rings (no links)."""
     from jax import lax
     from jax.sharding import PartitionSpec as P
-    from repro.models.sharding import shard_map_compat as _shard_map
     from repro.runtime import stap_pipeline as sp
 
     steady = ring.steady
@@ -164,8 +163,8 @@ def measure_hop_seconds(ring, *, iters: int = 8,
             x = lax.ppermute(x, axes, perm)
         return x
 
-    fn = jax.jit(_shard_map(per_device, mesh=ring.mesh, in_specs=(spec,),
-                            out_specs=spec, check_vma=False))
+    fn = jax.jit(jax.shard_map(per_device, mesh=ring.mesh, in_specs=(spec,),
+                               out_specs=spec, check_vma=False))
     x = jax.device_put(
         jnp.zeros((n_rows, ring.microbatch, ring.payload_width)),
         jax.sharding.NamedSharding(ring.mesh, spec))

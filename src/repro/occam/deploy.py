@@ -499,9 +499,10 @@ class Session:
             self._state = self._ring.init_state()
             # the all-masked drain round, in the ring's payload dtype
             # (quantized rings carry e.g. int8 slots)
-            self._empty_round = jnp.zeros(
+            self._empty_round = jax.device_put(jnp.zeros(
                 (self._ring.round_width, self.microbatch,
-                 self._ring.payload_width), self._ring._payload_dtype)
+                 self._ring.payload_width), self._ring._payload_dtype),
+                self._ring.in_sharding)
             self._masks = [np.zeros(self._ring.round_width, dtype=bool)
                            for _ in range(self.ring_depth)]
         else:
@@ -709,6 +710,13 @@ class Session:
         if self._ring is not None:
             return self._ring.trace_count
         return self.deployment._serve_step(self.round_batch)[1]["lowerings"]
+
+    @property
+    def input_sharding(self):
+        """Where submitted images should live before a tick consumes them:
+        the serving ring's input sharding, or None (the default device)
+        for a single-chip session."""
+        return None if self._ring is None else self._ring.in_sharding
 
     @property
     def in_flight_rounds(self) -> int:

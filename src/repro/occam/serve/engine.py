@@ -487,7 +487,7 @@ class AsyncEngine:
         taken = self.queue.take(n)
         parts = [lanes for _req, lanes, _take in taken]
         xs = parts[0] if len(parts) == 1 else jnp.concatenate(parts)
-        xs = jax.device_put(xs)
+        xs = jax.device_put(xs, self._session.input_sharding)
         segs = [(req, take) for req, _lanes, take in taken]
         return xs, segs, sum(take for _req, take in segs)
 

@@ -29,9 +29,7 @@ from typing import Callable, Sequence
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.sharding import Mesh, PartitionSpec as P
-
-from repro.models.sharding import shard_map_compat as _shard_map
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.partition import PartitionResult, partition_transformer
 from repro.core.stap import StapPlan, plan_replication
@@ -135,9 +133,13 @@ def pipeline_forward(stage_fn: Callable, stage_params,
         # move S x M x |act| zeros per call for one stage's payload)
         return outs
 
-    out = _shard_map(
+    out = jax.shard_map(
         per_stage, mesh=mesh,
         in_specs=(P(axis), P()), out_specs=P(axis),
         check_vma=False,
     )(stage_params, microbatches)
+    # the last stage's block is not a whole shard of the stage axis, and an
+    # Explicit-axes mesh (jax.make_mesh) refuses an implicit resharding
+    # slice — gather to replicated explicitly, then cut the block out
+    out = jax.sharding.reshard(out, NamedSharding(mesh, P()))
     return out[(s_stages - 1) * m:]

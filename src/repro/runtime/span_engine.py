@@ -117,7 +117,7 @@ def execute_partition(params: list[dict], xs: jax.Array, net: NetSpec,
     boundaries still route onto the float-only engines.
     """
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = span_ops.default_interpret()
     squeeze = xs.ndim == 3
     if squeeze:
         xs = xs[None]
@@ -342,7 +342,7 @@ def _pallas_spmd_body(net: NetSpec, a: int, b: int, spill, src_keys, *,
     TPU — it traces fine under shard_map; the compiled kernel on real
     TPUs). The schedule is built (and ring-retention validated) here, at
     pipeline build time, and baked into the jit cache key."""
-    interpret = jax.default_backend() != "tpu"
+    interpret = span_ops.default_interpret()
 
     def body(span_params, x, srcs):
         out, spilled = span_ops.span_pallas_call(

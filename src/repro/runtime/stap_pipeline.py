@@ -68,14 +68,14 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.experimental import mesh_utils
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.graph import NetSpec
 from repro.core.partition import PartitionResult
 from repro.core.stap import (StaggeredSchedule, StapPlan, plan_replication,
                              staggered_schedule, steady_schedule)
 from repro.models import cnn
-from repro.models.sharding import shard_map_compat as _shard_map
 from repro.occam import registry
 from repro.runtime import span_engine
 
@@ -198,10 +198,22 @@ def default_stap_plan(stage_times: Sequence[float], *,
                             harmonize=harmonize)
 
 
+def _device_pool(devices: Sequence | None) -> list:
+    """The caller's devices in the caller's order, else every device in
+    physical ring order (``mesh_utils.create_device_mesh``): on a TPU
+    slice consecutive devices are ICI neighbours, so each stage-to-stage
+    hop crosses one link — on a v5e 2x2, ``jax.devices()`` order would
+    put the second hop on a diagonal."""
+    if devices is not None:
+        return list(devices)
+    return list(mesh_utils.create_device_mesh((jax.device_count(),)).flat)
+
+
 def stap_mesh(n_stages: int, max_replicas: int,
               devices: Sequence | None = None) -> Mesh:
-    """A (stage, replica) mesh over the first n_stages*max_replicas devices."""
-    devs = list(devices if devices is not None else jax.devices())
+    """A (stage, replica) mesh over the first n_stages*max_replicas
+    devices of the pool (``_device_pool``)."""
+    devs = _device_pool(devices)
     need = n_stages * max_replicas
     if len(devs) < need:
         raise ValueError(
@@ -214,10 +226,10 @@ def stap_mesh(n_stages: int, max_replicas: int,
 
 
 def packed_mesh(n_chips: int, devices: Sequence | None = None) -> Mesh:
-    """A flat 1-D chip mesh over the first ``n_chips`` devices — the
-    sum-of-replicas layout (§III-E): a 4-3-2 plan occupies 9 chips, not
-    a rectangular 3x4 = 12."""
-    devs = list(devices if devices is not None else jax.devices())
+    """A flat 1-D chip mesh over the first ``n_chips`` devices of the
+    pool (``_device_pool``) — the sum-of-replicas layout (§III-E): a
+    4-3-2 plan occupies 9 chips, not a rectangular 3x4 = 12."""
+    devs = _device_pool(devices)
     if len(devs) < n_chips:
         raise ValueError(
             f"packed STAP mesh needs {n_chips} devices, have {len(devs)} "
@@ -488,10 +500,10 @@ def _round_executor(step, stage_params, feed: jax.Array, mesh: Mesh,
     # sums the replica partials on the host instead of an inter-replica
     # all-reduce of the mostly-zero padded stream (the same zero-broadcast
     # this module's pipeline_forward fix removed)
-    return _shard_map(per_device, mesh=mesh,
-                      in_specs=(P(stage_axis), P(stage_axis)),
-                      out_specs=P((stage_axis, replica_axis)),
-                      check_vma=False)(stage_params, feed)
+    return jax.shard_map(per_device, mesh=mesh,
+                         in_specs=(P(stage_axis), P(stage_axis)),
+                         out_specs=P((stage_axis, replica_axis)),
+                         check_vma=False)(stage_params, feed)
 
 
 def replicated_forward(stage_fn, stage_params, microbatches: jax.Array,
@@ -990,6 +1002,12 @@ class StapRing(_SpanProgram):
         """Images per serving round: round_width slots x microbatch."""
         return self.steady.round_width * self.microbatch
 
+    @property
+    def in_sharding(self) -> NamedSharding:
+        """Where a round's images belong on their way in: replicated over
+        the ring's mesh, as the tick's ``in_round`` spec takes them."""
+        return NamedSharding(self.mesh, P())
+
     def report(self) -> dict:
         """Machine-readable serving configuration."""
         return {
@@ -1075,13 +1093,13 @@ class StapRing(_SpanProgram):
             state = jnp.stack(hops) if s_stages > 1 else jnp.zeros_like(y)
             return state, out
 
-        mapped = _shard_map(per_device, mesh=mesh,
-                            in_specs=(P(STAGE_AXIS),
-                                      P((STAGE_AXIS, REPLICA_AXIS)),
-                                      P(), P()),
-                            out_specs=(P((STAGE_AXIS, REPLICA_AXIS)),
-                                       P((STAGE_AXIS, REPLICA_AXIS))),
-                            check_vma=False)
+        mapped = jax.shard_map(per_device, mesh=mesh,
+                               in_specs=(P(STAGE_AXIS),
+                                         P((STAGE_AXIS, REPLICA_AXIS)),
+                                         P(), P()),
+                               out_specs=(P((STAGE_AXIS, REPLICA_AXIS)),
+                                          P((STAGE_AXIS, REPLICA_AXIS))),
+                               check_vma=False)
         r_max, mb = steady.max_replicas, self.microbatch
         h, w, c = self.net.map_shape(self.net.n_layers)
         out_cast = self._lane_cast()
@@ -1153,10 +1171,10 @@ class StapRing(_SpanProgram):
             state = jnp.stack(hops) if s_stages > 1 else jnp.zeros_like(y)
             return state, out
 
-        mapped = _shard_map(per_device, mesh=mesh,
-                            in_specs=(P(CHIP_AXIS), P(CHIP_AXIS), P(), P()),
-                            out_specs=(P(CHIP_AXIS), P(CHIP_AXIS)),
-                            check_vma=False)
+        mapped = jax.shard_map(per_device, mesh=mesh,
+                               in_specs=(P(CHIP_AXIS), P(CHIP_AXIS), P(), P()),
+                               out_specs=(P(CHIP_AXIS), P(CHIP_AXIS)),
+                               check_vma=False)
         mb = self.microbatch
         h, w, c = self.net.map_shape(self.net.n_layers)
         last0 = asg.offsets[s_stages - 1]       # first last-stage chip
@@ -1198,7 +1216,9 @@ class StapRing(_SpanProgram):
 
     def pack_round(self, xs: jax.Array) -> jax.Array:
         """(n <= round_batch, H, W, C) images -> (W, mb, payload_width)
-        flat round, zero-padded on trailing lanes (mask them)."""
+        flat round, zero-padded on trailing lanes (mask them), placed at
+        ``in_sharding`` — every tick then sees one input sharding, and
+        the tick lowers once wherever the images came from."""
         xs = jnp.asarray(xs)
         pad = self.round_batch - xs.shape[0]
         if pad < 0:
@@ -1210,8 +1230,9 @@ class StapRing(_SpanProgram):
                                   self.policy.scale)
         xs = jnp.pad(xs, ((0, pad),) + ((0, 0),) * 3)
         flat = xs.reshape(self.round_width, self.microbatch, -1)
-        return jnp.pad(flat, ((0, 0), (0, 0),
+        flat = jnp.pad(flat, ((0, 0), (0, 0),
                               (0, self.payload_width - flat.shape[-1])))
+        return jax.device_put(flat, self.in_sharding)
 
 
 

@@ -46,5 +46,6 @@ def test_gitignore_covers_caches():
     assert os.path.exists(path), ".gitignore missing"
     with open(path) as f:
         rules = f.read()
-    for rule in ("__pycache__/", "*.pyc", ".pytest_cache/", "results/*.tmp"):
+    for rule in ("__pycache__/", "*.pyc", ".pytest_cache/", "results/*.tmp",
+                 ".hypothesis/", ".jax_cache/"):
         assert rule in rules, f".gitignore lost the {rule!r} rule"
