@@ -122,21 +122,21 @@ def check_kernels() -> None:
 async def serve_requests(engine, requests):
     """Submit every request, drain, and return outputs plus per-request
     wall seconds (submit to result, informational)."""
-    done_at = {}
+    async def timed(ticket):
+        y = await ticket
+        return y, time.perf_counter() - t0
+
     async with engine:
         t0 = time.perf_counter()
-        tickets = []
+        waits = []
         for tenant, xs in requests:
             ticket = await engine.submit(xs, tenant=tenant)
-            ticket.add_done_callback(
-                lambda t: done_at.__setitem__(t.uid, time.perf_counter()))
-            tickets.append(ticket)
+            waits.append(asyncio.ensure_future(timed(ticket)))
         await engine.drain()
-        outs = [await t for t in tickets]
+        outs, secs = zip(*await asyncio.gather(*waits))
         jax.block_until_ready(outs)
         report = engine.session.report()
         compiles = engine.compile_count
-    secs = [done_at[t.uid] - t0 for t in tickets]
     return outs, secs, report, compiles
 
 

@@ -2,11 +2,13 @@
 
 Two complementary instruments:
 
-* :class:`TickTimers` — a windowed, always-on dispatch timer the
-  serving session threads through every ring tick; feeds the live
+* :class:`TickTimers` — a windowed, always-on record of each serving
+  round's device time, fed by the session's completion watcher
+  (``occam.deploy.Session``: a round's time runs from when the device
+  could start it to when its outputs were ready); feeds the live
   ``utilization`` view in ``AsyncEngine.serving_stats()`` and the
-  ``timing`` block of ``Deployment.report()``. Deliberately cheap: one
-  clock read per tick, a bounded deque, no device synchronization.
+  ``timing`` block of ``Deployment.report()``. Cheap: a bounded deque
+  under a lock, written from the watcher's thread.
 * :func:`measure_stage_seconds` / :func:`measure_hop_seconds` —
   isolated, synchronized micro-measurements (jit each stage body or
   boundary hop alone, ``block_until_ready``, best-of-N) used by
@@ -22,6 +24,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import threading
 import time
 from typing import Callable, Sequence
 
@@ -36,7 +39,8 @@ class TickTimers:
     ``record(seconds)`` stamps one completed tick; events older than
     ``horizon_s`` roll off. ``busy_fraction()`` is the fraction of the
     observed window spent inside timed ticks — the duty cycle the
-    utilization stats scale per-stage shares by."""
+    utilization stats scale per-stage shares by. Safe to record from
+    one thread while another reads."""
 
     horizon_s: float = 60.0
     clock: Callable[[], float] = time.monotonic
@@ -44,17 +48,16 @@ class TickTimers:
         default_factory=collections.deque)   # (t_end, duration_s)
     total_s: float = 0.0     # lifetime, never rolls off
     count: int = 0
+    _lock: threading.RLock = dataclasses.field(
+        default_factory=threading.RLock, repr=False, compare=False)
 
     def record(self, duration_s: float) -> None:
-        now = self.clock()
-        self.events.append((now, float(duration_s)))
-        self.total_s += float(duration_s)
-        self.count += 1
-        self._roll(now)
-
-    def time(self):
-        """Context manager: ``with timers.time(): <one tick>``."""
-        return _TimerContext(self)
+        with self._lock:
+            now = self.clock()
+            self.events.append((now, float(duration_s)))
+            self.total_s += float(duration_s)
+            self.count += 1
+            self._roll(now)
 
     def _roll(self, now: float) -> None:
         while self.events and self.events[0][0] < now - self.horizon_s:
@@ -63,8 +66,9 @@ class TickTimers:
     def window(self, now: float | None = None) -> tuple[int, float]:
         """(ticks, busy seconds) inside the rolling horizon."""
         now = self.clock() if now is None else now
-        self._roll(now)
-        return len(self.events), sum(d for (_t, d) in self.events)
+        with self._lock:
+            self._roll(now)
+            return len(self.events), sum(d for (_t, d) in self.events)
 
     def mean_s(self, now: float | None = None) -> float:
         n, busy = self.window(now)
@@ -73,25 +77,13 @@ class TickTimers:
     def busy_fraction(self, now: float | None = None) -> float:
         """Busy seconds / observed span, over the rolling window."""
         now = self.clock() if now is None else now
-        n, busy = self.window(now)
-        if not n:
-            return 0.0
-        start = self.events[0][0] - self.events[0][1]
+        with self._lock:
+            n, busy = self.window(now)
+            if not n:
+                return 0.0
+            start = self.events[0][0] - self.events[0][1]
         span = max(now - start, busy, 1e-12)
         return min(busy / span, 1.0)
-
-
-class _TimerContext:
-    def __init__(self, timers: TickTimers):
-        self.timers = timers
-
-    def __enter__(self):
-        self._t0 = self.timers.clock()
-        return self
-
-    def __exit__(self, *exc):
-        self.timers.record(self.timers.clock() - self._t0)
-        return False
 
 
 # --------------------------------------------------------------------------

@@ -42,7 +42,12 @@ from __future__ import annotations
 
 import collections
 import dataclasses
-from typing import TYPE_CHECKING, Sequence
+import logging
+import queue
+import threading
+import time
+import weakref
+from typing import TYPE_CHECKING, Callable, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -59,6 +64,9 @@ if TYPE_CHECKING:  # pragma: no cover - typing only
     from repro.runtime.stap_pipeline import StapPipeline, StapRing
 
     from .search import Candidate, Frontier
+
+_log = logging.getLogger(__name__)
+
 
 class Deployment:
     """A compiled, runnable placement. Build via ``Placement.compile``."""
@@ -183,14 +191,15 @@ class Deployment:
         counts = {"lowerings": 0}
         plan = self.plan
 
-        def fn(params, xs):
+        # the function's name is the step's stable name in a profile
+        def occam_serve_step(params, xs):
             counts["lowerings"] += 1
             return span_engine.execute_partition(
                 params, xs, plan.net, plan.partition, counter=None,
                 interpret=self.interpret, routes=self.routes,
                 out_rows=plan.out_rows, policy=plan.quant)
 
-        cached = (jax.jit(fn), counts)
+        cached = (jax.jit(occam_serve_step), counts)
         self._steps[round_batch] = cached
         return cached
 
@@ -432,6 +441,81 @@ class Ticket:
     images: int
 
 
+class _Watcher:
+    """A session's completion watcher: one thread that waits, in dispatch
+    order, until the outputs handed to it are ready on the device
+    (``block_until_ready`` releases the interpreter lock while it waits)
+    and stamps that moment with ``time.perf_counter_ns``.
+
+    A tick's outputs carry the time its dispatch returned. The device
+    cannot start a round before it is dispatched, nor before the round
+    ahead of it is done, so the round's device time is
+    ``ready(i) - max(dispatch_end(i), ready(i-1))``; it goes to
+    ``timers``. Other outputs (arrays derived from a delivered round)
+    are only waited on. ``done(ready_ns)``, when given, is called on the
+    watcher's thread once the outputs are ready."""
+
+    def __init__(self, timers):
+        self.timers = timers
+        self._items: queue.SimpleQueue = queue.SimpleQueue()
+        self._thread: threading.Thread | None = None
+        self._cond = threading.Condition()
+        self._put = 0               # items handed over
+        self._completed = 0         # items whose outputs were ready
+        self._last_ready = 0        # ready stamp of the last tick
+
+    def put(self, outputs, *, tick: bool = False,
+            done: Callable[[int], None] | None = None) -> None:
+        dispatched = time.perf_counter_ns() if tick else None
+        if self._thread is None:
+            self._thread = threading.Thread(
+                target=self._run, name="occam-session-watcher", daemon=True)
+            self._thread.start()
+        self._put += 1
+        self._items.put((outputs, dispatched, done))
+
+    def _run(self) -> None:
+        while (item := self._items.get()) is not None:
+            outputs, dispatched, done = item
+            try:
+                jax.block_until_ready(outputs)
+            except Exception:  # noqa: BLE001 - the watcher must keep going
+                pass    # a failed computation raises where it is read
+            ready = time.perf_counter_ns()
+            if dispatched is not None:
+                self.timers.record(
+                    (ready - max(dispatched, self._last_ready)) / 1e9)
+                self._last_ready = ready
+            if done is not None:
+                try:
+                    done(ready)
+                except Exception:  # noqa: BLE001 - counted as completed
+                    _log.exception("completion callback failed")
+            # hold nothing while waiting for the next item: a callback
+            # may keep an engine, its session and its weights alive
+            item = outputs = done = None
+            with self._cond:
+                self._completed += 1
+                self._cond.notify_all()
+
+    def wait(self) -> None:
+        """Block until every output handed over so far was ready."""
+        target = self._put
+        with self._cond:
+            self._cond.wait_for(lambda: self._completed >= target)
+
+    def stop(self) -> None:
+        """Let the thread end once it has seen what it was handed."""
+        self._items.put(None)
+
+    def close(self) -> None:
+        """Wait for everything handed over, then end the thread."""
+        if self._thread is not None:
+            self.stop()
+            self._thread.join()
+            self._thread = None
+
+
 class _TicketState:
     __slots__ = ("ticket", "chunks", "remaining")
 
@@ -509,6 +593,10 @@ class Session:
             self._ring = None
             self._state = None
             self.timers = TickTimers()
+        # stamps each tick at device completion into ``timers``; a
+        # session dropped unclosed lets its thread end
+        self._watch = _Watcher(self.timers)
+        weakref.finalize(self, self._watch.stop)
         # per-image transfer profile for masked-lane accounting: sessions
         # count per_image x valid lanes, never per_span x round size
         self._per_image = deployment._per_image_profile()
@@ -648,14 +736,24 @@ class Session:
         return False
 
     def sync(self) -> "Session":
-        """Block until every dispatched tick has finished (ticks dispatch
+        """Block until every dispatched tick has finished and the
+        completion watcher has stamped it, so ``timers`` and
+        ``report().timing`` hold every tick (ticks dispatch
         asynchronously — time steady-state throughput against this)."""
-        if self._state is not None:
-            jax.block_until_ready(self._state)
+        self._watch.wait()
         for ts in self._tickets.values():
             if ts.chunks:
                 jax.block_until_ready(ts.chunks[-1])
         return self
+
+    def watch(self, outputs, done: Callable[[int], None]) -> None:
+        """Call ``done(ready_ns)`` on the completion watcher's thread once
+        ``outputs`` (arrays derived from delivered lanes) are ready on
+        the device; ``ready_ns`` is a ``time.perf_counter_ns`` stamp.
+        Outputs are waited on in the order they are handed over, after
+        every tick dispatched before them. An exception from ``done`` is
+        logged, and the outputs still count as completed."""
+        self._watch.put(outputs, done=done)
 
     def scale(self, *, arrival_rate: float) -> "Session":
         """Serve-time autoscaling: re-pick the deployment for an observed
@@ -691,6 +789,7 @@ class Session:
         if self._closed:
             return []
         out = self.results()
+        self._watch.close()
         self._closed = True
         self._state = None
         return out
@@ -829,6 +928,7 @@ class Session:
         self._masks = [mask] + self._masks[:-1]
         self._state, lanes = ring.tick(self.params, self._state, in_round,
                                        np.stack(self._masks))
+        self._watch.put((self._state, lanes), tick=True)
         if self.ring_depth > 1:
             self._in_flight.appendleft(segs if n_valid else None)
             exiting = self._in_flight.pop()
@@ -842,8 +942,9 @@ class Session:
         pad = self.round_batch - xs.shape[0]
         if pad:
             xs = jnp.pad(xs, ((0, pad),) + ((0, 0),) * 3)
-        with self.timers.time():
-            return step(self.params, xs)
+        y = step(self.params, xs)
+        self._watch.put(y, tick=True)
+        return y
 
     def _deliver(self, segs, lanes: jax.Array) -> None:
         off = 0

@@ -11,8 +11,11 @@ the same deployment, whatever the request mix.
 
 The serving loop, per scheduling step:
 
-1. deliver every round the ring has finished (resolve tickets, sample
-   latency into the metrics windows);
+1. deliver every round the ring has finished: hand its outputs to the
+   session's completion watcher, which calls back onto the event loop
+   once they are ready on the device; only then do the round's tickets
+   resolve and their latency (admission to device completion) enter
+   the metrics windows;
 2. dispatch the staged round — ONE device tick — then immediately pack
    and ``jax.device_put`` the *next* round while that tick runs (the
    one-round lookahead buffer: host-side packing is double-buffered
@@ -34,11 +37,15 @@ throughput for ``windows`` *consecutive* windows does the engine call
 the existing :meth:`~repro.occam.Deployment.reconcile` — fixing the
 instant re-pick ``Session.scale`` does — and a switch first drains the
 old ring completely, so in-flight tickets always resolve.
+
+Tracing: ``engine.spans`` (:class:`~repro.occam.serve.trace.SpanRecorder`,
+off by default) records the ``occam.*`` spans of requests and rounds.
 """
 from __future__ import annotations
 
 import asyncio
 import dataclasses
+import functools
 import time
 from typing import TYPE_CHECKING, Sequence
 
@@ -46,8 +53,10 @@ import jax
 import jax.numpy as jnp
 
 from ..deploy import Deployment
+from . import trace
 from .metrics import MetricsRing
 from .queue import AdmissionError, AdmissionQueue, Request
+from .trace import SpanRecorder
 
 if TYPE_CHECKING:  # pragma: no cover - typing only
     from ..search import Candidate, Frontier
@@ -105,12 +114,6 @@ class AsyncTicket:
     def __await__(self):
         return self._req.future.__await__()
 
-    def add_done_callback(self, fn) -> None:
-        """Call ``fn(ticket)`` on the event loop once the ticket
-        resolves — timing/observability hooks (e.g. completion
-        timestamps) without polling ``done()``."""
-        self._req.future.add_done_callback(lambda _f: fn(self))
-
     def __repr__(self) -> str:  # pragma: no cover - debugging nicety
         return (f"AsyncTicket(uid={self.uid}, tenant={self.tenant!r}, "
                 f"images={self.images}, done={self.done()})")
@@ -127,6 +130,7 @@ class AsyncEngine:
     ``max_wait_ms`` is the wall-clock latency SLO for sub-round
     traffic (default: partials wait for more traffic until ``drain``).
     ``clock`` injects a time source (tests, deterministic autoscaling).
+    A ticket resolves once its outputs are ready on the device.
     """
 
     def __init__(self, deployment: Deployment, params: Sequence[dict], *,
@@ -147,14 +151,23 @@ class AsyncEngine:
         self._session = deployment.serve(
             params, round_batch=round_batch,
             max_pending=_SESSION_MAX_PENDING)
-        self.queue = AdmissionQueue(max_pending=max_pending, clock=clock)
+        self.spans = SpanRecorder()
+        self.queue = AdmissionQueue(max_pending=max_pending, clock=clock,
+                                    spans=self.spans)
         self.metrics = MetricsRing(window_s=metrics_window_ms / 1e3,
                                    windows=metrics_windows, clock=clock)
-        # session-ticket uid -> [(request, take), ...] per dispatched round
-        self._rounds: dict[int, list] = {}
-        self._staged: tuple | None = None   # (xs_on_device, segs, n_valid)
+        # session-ticket uid -> _Round, per dispatched round
+        self._rounds: dict[int, _Round] = {}
+        self._staged: _Round | None = None
+        self._next_round = 0
+        # delivered rounds whose outputs the device has not finished
+        self._completing = 0
+        self._loop: asyncio.AbstractEventLoop | None = None
         self._task: asyncio.Task | None = None
         self._wake: asyncio.Event = asyncio.Event()
+        # set after each loop step and each round's completion: what
+        # drain() waits on before it looks at _idle again
+        self._settled: asyncio.Event = asyncio.Event()
         self._stopping = False
         self._flushing = False
         # autoscale policy (armed by .autoscale())
@@ -194,7 +207,8 @@ class AsyncEngine:
         start/stop)."""
         if self._task is None or self._task.done():
             self._stopping = False
-            self._task = asyncio.get_running_loop().create_task(
+            self._loop = asyncio.get_running_loop()
+            self._task = self._loop.create_task(
                 self._run(), name="occam-serve-engine")
         return self
 
@@ -212,6 +226,7 @@ class AsyncEngine:
         ``max_pending`` budget (its earlier tickets must deliver first);
         other tenants' budgets are untouched.
         """
+        t0 = trace.now() if self.spans.on else 0
         await self.start()
         xs = jnp.asarray(images)
         if xs.ndim == 3:
@@ -224,6 +239,10 @@ class AsyncEngine:
         req = self.queue.offer(tenant, xs, int(xs.shape[0]), fut)
         self.metrics.observe_arrival(req.n, self.queue.depth)
         self._wake.set()
+        if t0 and self.spans.on:
+            req.submitted_ns = t0
+            self.spans.record("occam.submit", t0, trace.now(), req.uid,
+                              req.uid, req.n)
         return AsyncTicket(req, self)
 
     def _cancel(self, req: Request) -> bool:
@@ -246,7 +265,8 @@ class AsyncEngine:
         self._flushing = True
         self._wake.set()
         while not self._idle:
-            await asyncio.sleep(0)
+            self._settled.clear()
+            await self._settled.wait()
 
     async def stop(self) -> None:
         """Drain, stop the loop, close the session."""
@@ -381,7 +401,7 @@ class AsyncEngine:
         exit), then open a session on the new deployment. Queued,
         not-yet-packed requests simply pack into the new geometry."""
         if self._staged is not None:
-            self._dispatch(*self._staged)
+            self._dispatch(self._staged)
             self._staged = None
         while self._rounds:
             if not self._session.pump():
@@ -408,6 +428,7 @@ class AsyncEngine:
         while True:
             now = self._clock()
             progressed = self._step(now)
+            self._settled.set()
             for _w in self.metrics.roll(now):
                 if self._frontier is not None:
                     self.autoscale_step()
@@ -430,7 +451,7 @@ class AsyncEngine:
     @property
     def _idle(self) -> bool:
         return (self.queue.depth == 0 and self._staged is None
-                and not self._rounds)
+                and not self._rounds and not self._completing)
 
     def _sleep_s(self, now: float) -> float | None:
         """How long the loop may sleep: until the oldest partial's SLO
@@ -460,7 +481,7 @@ class AsyncEngine:
         if self._staged is None and self.queue.depth >= rb:
             self._staged = self._stage(rb)
         if self._staged is not None:
-            self._dispatch(*self._staged)
+            self._dispatch(self._staged)
             self._staged = None
             progressed = True
             if self.queue.depth >= rb:
@@ -470,7 +491,7 @@ class AsyncEngine:
         elif self.queue.depth and self._aged(now):
             # SLO flush: a masked partial round, straight through the
             # ring — steady state continues, no drain
-            self._dispatch(*self._stage(min(self.queue.depth, rb)))
+            self._dispatch(self._stage(min(self.queue.depth, rb)))
             progressed = True
         elif self._rounds:
             # idle traffic, resident rounds: advance the ring one tick
@@ -480,36 +501,52 @@ class AsyncEngine:
         self.metrics.observe_queue_depth(self.queue.depth)
         return progressed
 
-    def _stage(self, n: int) -> tuple:
+    def _stage(self, n: int) -> "_Round":
         """Pack up to ``n`` queued images into one device-put round
         buffer (the lookahead buffer — host gather + H2D overlap the
         in-flight tick)."""
+        t0 = trace.now() if self.spans.on else 0
+        uid, self._next_round = self._next_round, self._next_round + 1
         taken = self.queue.take(n)
         parts = [lanes for _req, lanes, _take in taken]
         xs = parts[0] if len(parts) == 1 else jnp.concatenate(parts)
         xs = jax.device_put(xs, self._session.input_sharding)
         segs = [(req, take) for req, _lanes, take in taken]
-        return xs, segs, sum(take for _req, take in segs)
+        rnd = _Round(uid, xs, segs, sum(take for _req, take in segs))
+        if t0 and self.spans.on:
+            self.spans.record("occam.stage", t0, trace.now(), uid,
+                              images=rnd.n_valid, round=uid,
+                              requests=tuple(req.uid for req, _ in segs))
+        return rnd
 
-    def _dispatch(self, xs, segs, n_valid: int) -> None:
+    def _dispatch(self, rnd: "_Round") -> None:
         """One device tick: a full round ticks inside ``submit``; a
         partial is pumped through as a masked round."""
-        ticket = self._session.submit(xs)
-        if n_valid < self._session.round_batch:
+        t0 = trace.now() if self.spans.on else 0
+        ticket = self._session.submit(rnd.xs)
+        if rnd.n_valid < self._session.round_batch:
             self._session.pump(allow_partial=True)
-        self._rounds[ticket.uid] = segs
-        self.metrics.observe_round(n_valid, self._session.round_batch)
+        if t0 and self.spans.on:
+            rnd.dispatched_ns = trace.now()
+            self.spans.record("occam.dispatch", t0, rnd.dispatched_ns,
+                              rnd.uid, rnd.uid, rnd.n_valid, rnd.uid)
+        rnd.xs = None
+        self._rounds[ticket.uid] = rnd
+        self.metrics.observe_round(rnd.n_valid, self._session.round_batch)
 
     def _deliver(self) -> bool:
-        """Collect every round the ring has finished; resolve tickets
-        whose last lanes arrived and sample their latency."""
+        """Collect every round the ring has finished and hand its
+        outputs, with those of the tickets its last lanes complete, to
+        the session's completion watcher (:meth:`_complete` resolves
+        them once the device is done)."""
         done = self._session.results(flush=False)
         if not done:
             return False
-        now = self._clock()
         for ticket, lanes in done:
+            t0 = trace.now() if self.spans.on else 0
+            rnd = self._rounds.pop(ticket.uid)
             off = 0
-            for req, take in self._rounds.pop(ticket.uid):
+            for req, take in rnd.segs:
                 if req.cancelled:
                     # discard the lanes; the budget share still settles
                     off += take
@@ -523,8 +560,49 @@ class AsyncEngine:
                 if req.remaining == 0:
                     y = req.delivered[0] if len(req.delivered) == 1 \
                         else jnp.concatenate(req.delivered)
-                    self.metrics.observe_completion(req.n,
-                                                    now - req.arrived)
-                    if not req.future.done():
-                        req.future.set_result(y)
+                    rnd.resolved.append((req, y))
+            self._completing += 1
+            self._session.watch([lanes] + [y for _req, y in rnd.resolved],
+                                functools.partial(self._ready, rnd))
+            if t0 and self.spans.on:
+                self.spans.record("occam.deliver", t0, trace.now(),
+                                  rnd.uid, rnd.uid, rnd.n_valid, rnd.uid)
         return True
+
+    def _ready(self, rnd: "_Round", ready_ns: int) -> None:
+        """On the watcher's thread: the round's outputs are ready."""
+        try:
+            self._loop.call_soon_threadsafe(self._complete, rnd, ready_ns)
+        except RuntimeError:
+            pass    # the event loop closed: nothing awaits these tickets
+
+    def _complete(self, rnd: "_Round", ready_ns: int) -> None:
+        """Resolve the tickets a round completed, now that its outputs
+        are ready, and sample their latency from admission."""
+        self._completing -= 1
+        t = self._clock()
+        on = self.spans.on
+        if rnd.dispatched_ns and on:
+            self.spans.record("occam.device", rnd.dispatched_ns, ready_ns,
+                              rnd.uid, rnd.uid, rnd.n_valid, rnd.uid)
+        for req, y in rnd.resolved:
+            self.metrics.observe_completion(req.n, t - req.arrived)
+            if not req.future.done():
+                req.future.set_result(y)
+            if req.submitted_ns and on:
+                self.spans.record("occam.request", req.submitted_ns,
+                                  trace.now(), req.uid, images=req.n,
+                                  round=rnd.uid)
+        self._wake.set()
+        self._settled.set()
+
+
+@dataclasses.dataclass(eq=False)
+class _Round:
+    """One round of the engine, from staging to device completion."""
+    uid: int
+    xs: object                  # the staged images, until dispatched
+    segs: list                  # [(request, lanes taken), ...]
+    n_valid: int
+    dispatched_ns: int = 0      # trace.now() at dispatch return, if traced
+    resolved: list = dataclasses.field(default_factory=list)  # [(req, y)]

@@ -26,6 +26,9 @@ import collections
 import dataclasses
 import time
 
+from . import trace
+from .trace import SpanRecorder
+
 
 class AdmissionError(RuntimeError):
     """A tenant exceeded its ``max_pending`` budget; the submit was
@@ -57,6 +60,9 @@ class Request:
     delivered: list = dataclasses.field(default_factory=list)
     remaining: int = 0
     cancelled: bool = False
+    # trace.now() at submit entry and at admission, while spans record
+    submitted_ns: int = 0
+    admitted_ns: int = 0
 
     def __post_init__(self) -> None:
         self.remaining = self.n
@@ -72,14 +78,18 @@ class AdmissionQueue:
     they never pack into a round and stop counting against the tenant's
     budget immediately. ``depth`` counts queued (not yet packed)
     images; ``pending(tenant)`` counts everything admitted and not yet
-    delivered or cancelled — the quantity the budget bounds.
+    delivered or cancelled — the quantity the budget bounds. While
+    ``spans`` records, each request's wait from admission until its last
+    lane is taken is an ``occam.queued`` span.
     """
 
-    def __init__(self, *, max_pending: int = 64, clock=time.monotonic):
+    def __init__(self, *, max_pending: int = 64, clock=time.monotonic,
+                 spans: SpanRecorder | None = None):
         if max_pending < 1:
             raise ValueError("max_pending must be >= 1")
         self.max_pending = max_pending
         self.clock = clock
+        self.spans = spans if spans is not None else SpanRecorder()
         self._queue: collections.deque = collections.deque()  # [req, offset]
         self._depth = 0
         self._pending: collections.Counter = collections.Counter()
@@ -110,6 +120,8 @@ class AdmissionQueue:
             raise AdmissionError(tenant, held, n, self.max_pending)
         req = Request(self._next_uid, tenant, images, n,
                       arrived=self.clock(), future=future)
+        if self.spans.on:
+            req.admitted_ns = trace.now()
         self._next_uid += 1
         self._pending[tenant] += n
         self._queue.append([req, 0])
@@ -163,6 +175,10 @@ class AdmissionQueue:
             n += take
             if off + take == req.n:
                 self._queue.popleft()
+                if req.admitted_ns and self.spans.on:
+                    self.spans.record("occam.queued", req.admitted_ns,
+                                      trace.now(), req.uid, req.uid,
+                                      req.n)
             else:
                 entry[1] = off + take
         self._depth -= n

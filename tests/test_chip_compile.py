@@ -13,13 +13,14 @@ process at a time may load the TPU library, and every test worker
 imports this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 
 from repro.core import closure
-from repro.kernels.fused_span.kernel import _span_pallas
+from repro.kernels.fused_span.kernel import _span_pallas, span_pallas_call
 from repro.models import zoo
 
 
@@ -87,3 +88,29 @@ def test_span_kernel_compiles_for_v5e(name, a, b, one_chip,
     args, kw = _lower_args(net, a, b, one_chip)
     compiled = _span_pallas.lower(*args, **kw).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_span_kernel_carries_its_stable_name(one_chip, no_persistent_cache):
+    """Compiled inside a jitted step, as the serving step compiles it, a
+    span kernel's custom call is named for its span and still contains
+    ``_span_pallas`` (what the benchmark's kernel match reads)."""
+    net = zoo.get_network("alexnet")
+    a, b = 0, 3                 # stem, pool, conv
+    (xs, wb, _srcs), _kw = _lower_args(net, a, b, one_chip)
+    convs = [i for i, layer in enumerate(net.layers[a:b])
+             if layer.kind == "conv"]
+
+    def step(xs, wb):
+        layer_params = [{} for _ in net.layers[a:b]]
+        for j, i in enumerate(convs):
+            layer_params[i] = {"w": wb[2 * j], "b": wb[2 * j + 1]}
+        return span_pallas_call(xs, layer_params, net, a, b,
+                                interpret=False)[0]
+
+    text = jax.jit(step).lower(xs, wb).compile().as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1
+    name = calls[0].split(" = ", 1)[0].split()[-1].lstrip("%")
+    assert re.fullmatch(r"_span_pallas_0_3(\.\d+)?", name), name
+    assert "_span_pallas" in name
