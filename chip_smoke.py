@@ -117,6 +117,8 @@ def check_kernels() -> None:
           f", interpret={modes}")
     if modes != [False]:
         raise AssertionError(f"kernels lowered with interpret={modes}")
+    print(f"space-to-depth inputs (net, a, b, stride, channels): "
+          f"{sorted(set(span_kernel.INPUT_REWRITES))}")
 
 
 async def serve_requests(engine, requests):

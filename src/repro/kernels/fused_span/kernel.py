@@ -37,6 +37,18 @@ and slots; each slot reads its scheduled row index from SMEM and is
 ``pl.when``-guarded. The input/output BlockSpec index maps also read the
 schedule, streaming exactly one input block in and one ``out_rows``-row
 output block out per step.
+
+Space-to-depth input (``span_input_rewrite``): where a span opens with a
+stride-s conv whose input has few channels (s*s*C_in <= 128, one lane
+tile) and only that conv reads the input map, ``span_pallas_call`` folds
+each s x s pixel block of the input into channels and runs the kernel on
+the equivalent stride-1 conv with a ceil(k/s)-wide filter, zero-padded
+and regrouped to match (AlexNet's 11x11 stride-4 stem over 3 channels
+becomes a 3x3 conv over 48). The fold runs as XLA ops ahead of the
+kernel, so the strided stem needs no column-phase selection matmuls, its
+taps read K = s*s*C_in instead of C_in, and the input ring no longer
+pads 3 channels to 128 lanes. Strided layers inside a span read maps
+the kernel itself produces row by row, so they keep the column phases.
 """
 from __future__ import annotations
 
@@ -50,7 +62,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 
 from repro.core import closure
-from repro.core.graph import NetSpec
+from repro.core.graph import LayerSpec, NetSpec, chain
 
 from .rowops import NEG_INF, conv_row, pool_row, project_row, ring_window
 
@@ -58,6 +70,10 @@ from .rowops import NEG_INF, conv_row, pool_row, project_row, ring_window
 # (net name, a, b, interpret) of every span kernel this process has
 # lowered, appended at trace time: evidence of which mode really ran.
 LOWERINGS: list[tuple[str, int, int, bool]] = []
+
+# (net name, a, b, stride, folded channels) of every span lowered through
+# the space-to-depth input rewrite, appended at trace time.
+INPUT_REWRITES: list[tuple[str, int, int, int, int]] = []
 
 # Left over the kernel's own buffers for the values its body keeps live
 # (windows, column phases, accumulators): the compiler's default scoped
@@ -170,15 +186,22 @@ def _span_kernel(sched_ref, outrow_ref, inrow_ref, x_ref, *refs,
 
 @functools.partial(jax.jit,
                    static_argnames=("net", "a", "b", "schedule", "spill",
-                                    "src_keys", "interpret"))
+                                    "src_keys", "interpret", "rewrite"))
 def _span_pallas(xs: jax.Array, wb: tuple[jax.Array, ...],
                  srcs: tuple[jax.Array, ...], *, net: NetSpec,
                  a: int, b: int, schedule: closure.SpanSchedule,
                  spill: tuple[int, ...], src_keys: tuple[int, ...],
-                 interpret: bool):
+                 interpret: bool,
+                 rewrite: tuple[str, int, int, int, int] | None = None):
+    """``rewrite``: the ``INPUT_REWRITES`` entry when ``net`` is the
+    space-to-depth form of a published span; records and the kernel's
+    name then speak of the published net and span."""
     from jax.experimental.pallas import tpu as pltpu
 
-    LOWERINGS.append((net.name, a, b, interpret))
+    name, pa, pb = rewrite[:3] if rewrite else (net.name, a, b)
+    LOWERINGS.append((name, pa, pb, interpret))
+    if rewrite:
+        INPUT_REWRITES.append(rewrite)
     batch = xs.shape[0]
     n_maps = b - a + 1
     h_b, w_b, c_b = net.map_shape(b)
@@ -258,9 +281,78 @@ def _span_pallas(xs: jax.Array, wb: tuple[jax.Array, ...],
         interpret=interpret,
         # the name its calls carry in a profile; every span kernel's
         # contains "_span_pallas", which the benchmark matches on
-        name=f"_span_pallas_{a}_{b}",
+        name=f"_span_pallas_{pa}_{pb}",
     )(sched_tab, outrow_tab, inrow_tab, xs, *srcs, *wb)
     return outs[0], tuple(outs[1:])
+
+
+def span_input_rewrite(net: NetSpec, a: int, b: int
+                       ) -> tuple[NetSpec, int, int, int]:
+    """``(net', a', b', s)``: the net and span the kernel runs for SPAN(a, b)
+    of ``net``, and the stride ``s`` its input is folded by.
+
+    The rewrite applies where the span's first layer is a conv of stride
+    s > 1 with s*s*C_in <= 128 (the folded channels fit one lane tile, so
+    K costs no extra MXU pass), and no layer but that conv reads map ``a``
+    (it is no residual source; a span spills only interior maps) and no
+    residual edge enters the span from before it. ``net'`` is then the span alone, map ``a`` becoming map 0:
+    its first layer a ceil(k/s)-wide stride-1 conv over the
+    (out + ceil(k/s) - 1)-square, s*s*C_in-channel space-to-depth input,
+    the other layers and in-span residual edges as published. Elsewhere
+    it returns ``(net, a, b, 1)``.
+    """
+    first = net.layers[a]
+    s = first.stride
+    if (first.kind != "conv" or s < 2 or s * s * first.in_ch > 128
+            or any(src == a or src < a < t <= b
+                   for src, t in net.residual_edges)):
+        return net, a, b, 1
+    kq = -(-first.k // s)
+    specs = [("conv", kq, 1, 0, first.out_ch)] + [
+        (layer.kind, layer.k, layer.stride, layer.padding, layer.out_ch)
+        for layer in net.layers[a + 1:b]]
+    edges = tuple((src - a, t - a) for src, t in net.residual_edges
+                  if a <= src and t <= b)
+    folded = chain(f"{net.name}.s2d", specs,
+                   in_h=first.out_h + kq - 1, in_w=first.out_w + kq - 1,
+                   in_ch=s * s * first.in_ch, residual_edges=edges)
+    return folded, 0, b - a, s
+
+
+def _space_to_depth(xs: jax.Array, layer: LayerSpec, s: int) -> jax.Array:
+    """(B, H, W, C) -> (B, H', W', s*s*C): the conv's zero padding, then
+    pad or crop at the bottom and right to whole s-blocks of the rows and
+    columns the stride-1 form reads (a crop drops only rows and columns
+    the published conv never reads), each s x s block folded into
+    channels in (row phase, column phase, channel) order.
+
+    The fold is a stride-s conv with a one-hot filter at ``HIGHEST``
+    precision, exact as ``rowops.select_cols`` is: XLA lays a 3-channel
+    map out for the TPU's convolution unit in fewer passes than it does
+    a reshape and transpose (0.75 against 1.00 ms a round of AlexNet's
+    whole-body span on a v5e)."""
+    kq = -(-layer.k // s)
+    hp, wp = (layer.out_h + kq - 1) * s, (layer.out_w + kq - 1) * s
+    p = layer.padding
+    xs = jnp.pad(xs, ((0, 0), (p, max(0, hp - layer.in_h - p)),
+                      (p, max(0, wp - layer.in_w - p)), (0, 0)))
+    c = xs.shape[-1]
+    onehot = jnp.eye(s * s * c, dtype=xs.dtype).reshape(s, s, c, s * s * c)
+    return lax.conv_general_dilated(
+        xs[:, :hp, :wp], onehot, (s, s), "VALID",
+        dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        precision=lax.Precision.HIGHEST)
+
+
+def _space_to_depth_filter(w: jax.Array, s: int) -> jax.Array:
+    """(k, k, C_in, C_out) -> (k', k', s*s*C_in, C_out), k' = ceil(k/s):
+    zero taps pad k to k'*s, and tap (q*s + p) of each axis moves to tap
+    q, phase p, in ``_space_to_depth``'s channel order."""
+    k, _, cin, cout = w.shape
+    kq = -(-k // s)
+    w = jnp.pad(w, ((0, kq * s - k), (0, kq * s - k), (0, 0), (0, 0)))
+    w = w.reshape(kq, s, kq, s, cin, cout).transpose(0, 2, 1, 3, 4, 5)
+    return w.reshape(kq, kq, s * s * cin, cout)
 
 
 def span_pallas_call(xs: jax.Array, layer_params: list[dict], net: NetSpec,
@@ -281,13 +373,15 @@ def span_pallas_call(xs: jax.Array, layer_params: list[dict], net: NetSpec,
 
     Returns ``(L_b maps, {spilled map index -> array})``.
 
+    Where ``span_input_rewrite`` applies, the input and the first filter
+    are folded space-to-depth here, in the caller's trace, and the kernel
+    runs the rewritten span.
+
     The schedule is rebuilt (cheaply) on every call so ring retention is
     re-validated against the *current* ``closure.span_row_counts``; the jit
     cache is keyed on the schedule itself.
     """
     spill = tuple(sorted(set(spill)))
-    schedule = closure.span_schedule(net, a, b, spill=spill,
-                                     out_rows=out_rows)
     src_keys = tuple(sorted({s for (s, t) in net.residual_edges
                              if s < a < t <= b}))
     missing = [s for s in src_keys if s not in (srcs or {})]
@@ -300,10 +394,19 @@ def span_pallas_call(xs: jax.Array, layer_params: list[dict], net: NetSpec,
         if layer.kind == "conv":
             wb.append(layer_params[off]["w"])
             wb.append(layer_params[off]["b"])
+    knet, ka, kb, fold = span_input_rewrite(net, a, b)
+    rewrite = None
+    if fold > 1:
+        xs = _space_to_depth(xs, net.layers[a], fold)
+        wb[0] = _space_to_depth_filter(wb[0], fold)
+        rewrite = (net.name, a, b, fold, knet.map_shape(0)[2])
+    kspill = tuple(m - a + ka for m in spill)
+    schedule = closure.span_schedule(knet, ka, kb, spill=kspill,
+                                     out_rows=out_rows)
     out, spills = _span_pallas(
         xs, tuple(wb), tuple((srcs or {})[s] for s in src_keys),
-        net=net, a=a, b=b, schedule=schedule, spill=spill,
-        src_keys=src_keys, interpret=interpret)
+        net=knet, a=ka, b=kb, schedule=schedule, spill=kspill,
+        src_keys=src_keys, interpret=interpret, rewrite=rewrite)
     return out, dict(zip(spill, spills))
 
 
@@ -314,7 +417,10 @@ def span_kernel_vmem_elems(net: NetSpec, a: int, b: int,
     ring_scratch_elems == |DC(a, b)| at the given tile height and their sum
     == span_footprint_elems — the property tests pin this identity (scratch
     bytes = footprint x dtype size, minus the weights held as VMEM inputs
-    rather than scratch).
+    rather than scratch). Both are of the span the kernel runs: for a span
+    that takes the space-to-depth input, the closure and filters of the
+    rewritten span (``span_input_rewrite``).
     """
-    schedule = closure.span_schedule(net, a, b, out_rows=out_rows)
-    return schedule.scratch_elems(), net.span_weight_elems(a, b)
+    knet, ka, kb, _ = span_input_rewrite(net, a, b)
+    schedule = closure.span_schedule(knet, ka, kb, out_rows=out_rows)
+    return schedule.scratch_elems(), knet.span_weight_elems(ka, kb)

@@ -17,6 +17,13 @@ kernel compiler) lowers neither ``x[a:b:s]`` nor a strided ``lax.slice``.
 A strided layer instead splits each window row into its ``stride`` column
 phases with one-hot selection matmuls (``column_phases``), after which
 every tap reads a contiguous slice of one phase.
+
+A span whose first layer is a strided conv over few channels does not
+come here strided: ``kernel.span_input_rewrite`` folds its input
+space-to-depth before the kernel, so ``conv_row`` runs it at stride 1.
+Strided layers inside a span (pools, downsampling convs) read rows the
+kernel produces one at a time in VMEM, where no such fold is made, so
+they keep the column phases.
 """
 from __future__ import annotations
 

@@ -20,7 +20,8 @@ import jax.numpy as jnp
 import pytest
 
 from repro.core import closure
-from repro.kernels.fused_span.kernel import _span_pallas, span_pallas_call
+from repro.kernels.fused_span.kernel import (INPUT_REWRITES, LOWERINGS,
+                                             _span_pallas, span_pallas_call)
 from repro.models import zoo
 
 
@@ -90,13 +91,10 @@ def test_span_kernel_compiles_for_v5e(name, a, b, one_chip,
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_span_kernel_carries_its_stable_name(one_chip, no_persistent_cache):
-    """Compiled inside a jitted step, as the serving step compiles it, a
-    span kernel's custom call is named for its span and still contains
-    ``_span_pallas`` (what the benchmark's kernel match reads)."""
-    net = zoo.get_network("alexnet")
-    a, b = 0, 3                 # stem, pool, conv
-    (xs, wb, _srcs), _kw = _lower_args(net, a, b, one_chip)
+def _compile_step(net, a, b, sharding):
+    """The HLO text of a jitted step that runs SPAN(a, b) through
+    ``span_pallas_call``, as the serving step compiles it."""
+    (xs, wb, _srcs), _kw = _lower_args(net, a, b, sharding)
     convs = [i for i, layer in enumerate(net.layers[a:b])
              if layer.kind == "conv"]
 
@@ -107,10 +105,50 @@ def test_span_kernel_carries_its_stable_name(one_chip, no_persistent_cache):
         return span_pallas_call(xs, layer_params, net, a, b,
                                 interpret=False)[0]
 
-    text = jax.jit(step).lower(xs, wb).compile().as_text()
-    calls = [line for line in text.splitlines()
-             if 'custom_call_target="tpu_custom_call"' in line]
+    return jax.jit(step).lower(xs, wb).compile().as_text()
+
+
+def _kernel_calls(text):
+    """(name, scoped VMEM bytes requested) of each kernel custom call."""
+    calls = []
+    for line in text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' not in line:
+            continue
+        name = line.split(" = ", 1)[0].split()[-1].lstrip("%")
+        vmem = re.search(r'"scoped_memory_configs":\[\{[^]]*"size":"(\d+)"',
+                         line)
+        calls.append((name, int(vmem.group(1))))
+    return calls
+
+
+def test_span_kernel_carries_its_stable_name(one_chip, no_persistent_cache):
+    """Compiled inside a jitted step, as the serving step compiles it, a
+    span kernel's custom call is named for its span and still contains
+    ``_span_pallas`` (what the benchmark's kernel match reads)."""
+    net = zoo.get_network("alexnet")
+    a, b = 0, 3                 # stem, pool, conv
+    calls = _kernel_calls(_compile_step(net, a, b, one_chip))
     assert len(calls) == 1
-    name = calls[0].split(" = ", 1)[0].split()[-1].lstrip("%")
+    name = calls[0][0]
     assert re.fullmatch(r"_span_pallas_0_3(\.\d+)?", name), name
     assert "_span_pallas" in name
+
+
+@pytest.mark.parametrize("a,b", [(0, 8), (0, 1)])
+def test_strided_stem_compiles_on_space_to_depth_input(a, b, one_chip,
+                                                       no_persistent_cache):
+    """AlexNet's 11x11 stride-4 stem compiles as a 3x3 conv over the
+    48-channel folded input: one kernel, named and recorded for the
+    published span, asking for less VMEM than the un-rewritten kernel
+    (3 channels pad to 128 lanes in its input blocks and ring)."""
+    net = zoo.get_network("alexnet")
+    calls = _kernel_calls(_compile_step(net, a, b, one_chip))
+    assert len(calls) == 1
+    name, vmem = calls[0]
+    assert re.fullmatch(rf"_span_pallas_{a}_{b}(\.\d+)?", name), name
+    assert ("alexnet", a, b, False) in LOWERINGS
+    assert ("alexnet", a, b, 4, 48) in INPUT_REWRITES
+    args, kw = _lower_args(net, a, b, one_chip)
+    published = _kernel_calls(_span_pallas.lower(*args, **kw).compile()
+                              .as_text())
+    assert vmem < published[0][1]

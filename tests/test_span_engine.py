@@ -2,7 +2,13 @@
 jitted scan executor agree with the layer-by-layer oracle across kernel
 sizes, strides, conv/pool mixes and batch; the kernel's VMEM scratch is
 exactly the dependence closure; and the dispatcher routes a PartitionResult
-correctly while preserving model==machine traffic accounting."""
+correctly while preserving model==machine traffic accounting. Spans that
+open with a strided conv over few channels run on a space-to-depth input;
+the same equalities hold there, and the rewrite engages only where the
+rule says."""
+import json
+import pathlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -11,9 +17,11 @@ import pytest
 from repro.core import closure
 from repro.core.graph import chain
 from repro.core.partition import partition_cnn
-from repro.kernels.fused_span.kernel import span_kernel_vmem_elems
+from repro.kernels.fused_span import kernel
+from repro.kernels.fused_span.kernel import (span_input_rewrite,
+                                             span_kernel_vmem_elems)
 from repro.kernels.fused_span.ops import span_forward
-from repro.models import cnn
+from repro.models import cnn, zoo
 from repro.runtime import span_engine
 
 C, P = "conv", "pool"
@@ -80,13 +88,119 @@ def test_kernel_scratch_is_exactly_the_closure(name, specs, hw, ch):
     |DC(a,b)| x dtype size, and scratch + resident filters equal
     span_footprint_elems x dtype size (Eqn. 1's left-hand side)."""
     net = chain("t", specs, in_h=hw, in_w=hw, in_ch=ch)
-    a, b = 0, net.n_layers
-    scratch, weights = span_kernel_vmem_elems(net, a, b)
+    # the span the kernel runs: the published one, or its space-to-depth
+    # form where the first layer is a strided conv (k3-s2)
+    knet, a, b, _ = span_input_rewrite(net, 0, net.n_layers)
+    scratch, weights = span_kernel_vmem_elems(net, 0, net.n_layers)
     itemsize = jnp.dtype(jnp.float32).itemsize
     assert scratch * itemsize == \
-        closure.span_closure_elems(net, a, b) * itemsize
+        closure.span_closure_elems(knet, a, b) * itemsize
     assert (scratch + weights) * itemsize == \
-        closure.span_footprint_elems(net, a, b) * itemsize
+        closure.span_footprint_elems(knet, a, b) * itemsize
+
+
+S2D_CASES = [
+    # (name, specs, hw, in_ch, residual_edges); every first layer is a
+    # strided conv with s*s*C_in <= 128, so the span takes the
+    # space-to-depth input
+    ("k11-s4-pad", [(C, 11, 4, 0, 8), (P, 3, 2, 0, 0), (C, 3, 1, 1, 8)],
+     31, 3, ()),                             # 31 -> 32 rows and columns
+    ("k11-s4-crop", [(C, 11, 4, 0, 8), (C, 3, 1, 1, 8)], 30, 3, ()),
+    ("k7-s2-p3", [(C, 7, 2, 3, 8), (P, 3, 2, 1, 0)], 15, 3, ()),
+    ("k3-s2-p1", [(C, 3, 2, 1, 4), (C, 3, 1, 1, 8)], 9, 3, ()),
+    ("k7-s2-res", [(C, 7, 2, 3, 4), (P, 3, 2, 1, 0), (C, 3, 1, 1, 4),
+                   (C, 3, 1, 1, 4)], 16, 3, ((2, 4),)),
+]
+
+
+@pytest.mark.pallas_interpret
+@pytest.mark.parametrize("out_rows", [1, 2])
+@pytest.mark.parametrize("name,specs,hw,ch,edges", S2D_CASES)
+def test_strided_stem_takes_space_to_depth_input(name, specs, hw, ch, edges,
+                                                 out_rows):
+    """A span opening with a strided conv runs as a stride-1 conv over
+    the folded input and still equals the oracle (batch 2, one or two
+    output rows a step, a residual source downstream of the input), and
+    INPUT_REWRITES records it under the published net and span."""
+    net = chain(f"s2d-{name}", specs, in_h=hw, in_w=hw, in_ch=ch,
+                residual_edges=edges)
+    params = cnn.init_params(jax.random.PRNGKey(0), net)
+    xs = jax.random.normal(jax.random.PRNGKey(1), (2, hw, hw, ch))
+    ref = jax.vmap(lambda im: cnn.reference_forward(params, im, net))(xs)
+    got = span_forward(xs, params, net, 0, net.n_layers, interpret=True,
+                       out_rows=out_rows)
+    assert_close(got, ref, err_msg=name)
+    s = specs[0][2]
+    assert (net.name, 0, net.n_layers, s, s * s * ch) in \
+        kernel.INPUT_REWRITES
+    assert (net.name, 0, net.n_layers, True) in kernel.LOWERINGS
+
+
+@pytest.mark.pallas_interpret
+def test_space_to_depth_span_after_the_first_map_spills():
+    """The rewrite of a span that starts past map 0 (span (1, 3), its
+    first layer a stride-2 conv over 4 channels) keeps the published map
+    indices of what it spills: map 2 leaves the span for edge (2, 4)."""
+    net = chain("s2d-mid", [(C, 3, 1, 1, 4), (C, 3, 2, 1, 8),
+                            (C, 3, 1, 1, 8), (C, 3, 1, 1, 8)],
+                in_h=12, in_w=12, in_ch=3, residual_edges=((2, 4),))
+    params = cnn.init_params(jax.random.PRNGKey(0), net)
+    xs = jax.random.normal(jax.random.PRNGKey(1), (2, 12, 12, 3))
+    maps = jax.vmap(lambda im: cnn.reference_forward(
+        params, im, net, collect=True))(xs)
+    got, spilled = span_forward(maps[1], params[1:3], net, 1, 3,
+                                interpret=True, spill=(2,))
+    assert_close(got, maps[3])
+    assert_close(spilled[2], maps[2])
+    assert ("s2d-mid", 1, 3, 2, 16) in kernel.INPUT_REWRITES
+
+
+def test_space_to_depth_rule():
+    """The rewrite engages by shape alone: a strided conv opens the span,
+    its folded channels fit one lane tile, and only it reads map a."""
+    alexnet = zoo.get_network("alexnet")
+    knet, a, b, s = span_input_rewrite(alexnet, 0, 8)
+    assert (a, b, s) == (0, 8, 4)
+    assert knet.map_shape(0) == (57, 57, 48)
+    first = knet.layers[0]
+    assert (first.kind, first.k, first.stride, first.padding,
+            first.out_ch) == ("conv", 3, 1, 0, 64)
+    assert knet.map_shape(b) == alexnet.map_shape(8)
+    for name in ("resnet18", "resnet50"):          # 7x7 stride-2 stems
+        assert span_input_rewrite(zoo.get_network(name), 0, 2)[3] == 2
+    # interior strided layers and pools keep the column phases
+    assert span_input_rewrite(alexnet, 1, 3)[3] == 1
+    # map a is a residual source: the shortcut reads its rows too
+    res = chain("r", [(C, 3, 2, 1, 4), (C, 3, 1, 1, 4)], in_h=8, in_w=8,
+                in_ch=3, residual_edges=((0, 2),))
+    assert span_input_rewrite(res, 0, 2) == (res, 0, 2, 1)
+    # s*s*C_in = 4 * 64 > 128 lanes
+    wide = chain("w", [(C, 3, 2, 1, 8)], in_h=8, in_w=8, in_ch=64)
+    assert span_input_rewrite(wide, 0, 1) == (wide, 0, 1, 1)
+    # no span of vgg19.sat's plan opens with a strided layer
+    config = json.loads((pathlib.Path(__file__).parents[1] / "benchmarks"
+                         / "chip" / "configs" / "vgg19.json").read_text())
+    vgg = zoo.get_network(config["net"]["name"])
+    assert len(config["routes"]) == 9
+    for a, b, _ in config["routes"]:
+        assert span_input_rewrite(vgg, a, b) == (vgg, a, b, 1)
+
+
+@pytest.mark.parametrize("name,specs,hw,ch,edges", S2D_CASES)
+def test_kernel_scratch_is_the_closure_of_the_rewritten_net(name, specs, hw,
+                                                            ch, edges):
+    """For a span that takes the space-to-depth input, the kernel's ring
+    scratch is exactly the closure of the rewritten span, and scratch +
+    its regrouped filters its footprint. (The DP still sizes the
+    published span: ``closure`` is not told of the rewrite.)"""
+    net = chain("t", specs, in_h=hw, in_w=hw, in_ch=ch, residual_edges=edges)
+    n = net.n_layers
+    knet, a, b, s = span_input_rewrite(net, 0, n)
+    assert s == specs[0][2] and knet.map_shape(0)[2] == s * s * ch
+    scratch, weights = span_kernel_vmem_elems(net, 0, n)
+    assert scratch == closure.span_closure_elems(knet, a, b)
+    assert scratch + weights == closure.span_footprint_elems(knet, a, b)
+    assert weights == knet.span_weight_elems(a, b)
 
 
 RESIDUAL_CASES = [
