@@ -119,6 +119,8 @@ def check_kernels() -> None:
         raise AssertionError(f"kernels lowered with interpret={modes}")
     print(f"space-to-depth inputs (net, a, b, stride, channels): "
           f"{sorted(set(span_kernel.INPUT_REWRITES))}")
+    print(f"column selections per image (net, a, b, count): "
+          f"{sorted(set(span_kernel.COLUMN_SELECTS))}")
 
 
 async def serve_requests(engine, requests):

@@ -47,8 +47,13 @@ and regrouped to match (AlexNet's 11x11 stride-4 stem over 3 channels
 becomes a 3x3 conv over 48). The fold runs as XLA ops ahead of the
 kernel, so the strided stem needs no column-phase selection matmuls, its
 taps read K = s*s*C_in instead of C_in, and the input ring no longer
-pads 3 channels to 128 lanes. Strided layers inside a span read maps
+pads 3 channels to 128 lanes. Strided convs inside a span read maps
 the kernel itself produces row by row, so they keep the column phases.
+
+Strided pools pick their columns once: ``rowops.pool_row`` takes the
+window max at every column first (contiguous maxima on the vector
+units), then one one-hot selection per output row. ``COLUMN_SELECTS``
+records how many selections each lowered span makes per image.
 """
 from __future__ import annotations
 
@@ -64,7 +69,8 @@ from jax.experimental import pallas as pl
 from repro.core import closure
 from repro.core.graph import LayerSpec, NetSpec, chain
 
-from .rowops import NEG_INF, conv_row, pool_row, project_row, ring_window
+from .rowops import (NEG_INF, column_selects, conv_row, pool_row,
+                     project_row, ring_window)
 
 
 # (net name, a, b, interpret) of every span kernel this process has
@@ -74,6 +80,11 @@ LOWERINGS: list[tuple[str, int, int, bool]] = []
 # (net name, a, b, stride, folded channels) of every span lowered through
 # the space-to-depth input rewrite, appended at trace time.
 INPUT_REWRITES: list[tuple[str, int, int, int, int]] = []
+
+# (net name, a, b, selection matmuls per image) of every span kernel
+# lowered, appended at trace time: ``rowops.column_selects`` of the span
+# the kernel runs, under the published net and span.
+COLUMN_SELECTS: list[tuple[str, int, int, int]] = []
 
 # Left over the kernel's own buffers for the values its body keeps live
 # (windows, column phases, accumulators): the compiler's default scoped
@@ -200,6 +211,7 @@ def _span_pallas(xs: jax.Array, wb: tuple[jax.Array, ...],
 
     name, pa, pb = rewrite[:3] if rewrite else (net.name, a, b)
     LOWERINGS.append((name, pa, pb, interpret))
+    COLUMN_SELECTS.append((name, pa, pb, column_selects(net, a, b)))
     if rewrite:
         INPUT_REWRITES.append(rewrite)
     batch = xs.shape[0]

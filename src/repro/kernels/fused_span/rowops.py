@@ -10,26 +10,33 @@ both engines share the row math and differ only in how rows are stored.
 Convs are executed as k*k MXU matmuls (W_out, C_in) @ (C_in, C_out) over
 horizontally shifted/strided row windows, accumulating in fp32
 (channels-minor layout; the MXU-friendly form of the paper's row-streamed
-convolution). Pools are k*k running maxima with -inf padding.
+convolution). Pools are k x k maxima with -inf padding.
 
 Horizontal strides never slice a value with a step: Mosaic (the TPU
 kernel compiler) lowers neither ``x[a:b:s]`` nor a strided ``lax.slice``.
-A strided layer instead splits each window row into its ``stride`` column
-phases with one-hot selection matmuls (``column_phases``), after which
-every tap reads a contiguous slice of one phase.
+Strided columns are picked instead by one-hot selection matmuls
+(``select_cols``). A pool reduces first and picks once: the max over its
+k window rows, then over k contiguous column shifts, is the window max
+at every column, and one selection takes columns 0, s, 2s, ... of it
+(max is exact, so the order changes no bit). A strided conv sums
+products, so it splits each window row into its ``stride`` column
+phases (``column_phases``), after which every tap reads a contiguous
+slice of one phase; a subsampling shortcut (``project_row``) picks its
+columns once. ``column_selects`` counts the selections a span makes.
 
 A span whose first layer is a strided conv over few channels does not
 come here strided: ``kernel.span_input_rewrite`` folds its input
 space-to-depth before the kernel, so ``conv_row`` runs it at stride 1.
-Strided layers inside a span (pools, downsampling convs) read rows the
-kernel produces one at a time in VMEM, where no such fold is made, so
-they keep the column phases.
+Strided convs inside a span read rows the kernel produces one at a time
+in VMEM, where no such fold is made, so they keep the column phases.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from repro.core.graph import NetSpec
 
 NEG_INF = -1e30
 
@@ -108,18 +115,24 @@ def conv_row(window, w, b, stride: int, padding: int, out_w: int):
 
 def pool_row(window, k: int, stride: int, padding: int, out_w: int):
     """One max-pool output row from a (k, W_in, C) window (vertical halo
-    included, out-of-range rows already -inf). Returns (out_w, C)."""
+    included, out-of-range rows already -inf). Returns (out_w, C).
+
+    The max over the window rows, then over k contiguous column shifts,
+    is the k x k window max at every column; a strided pool then picks
+    columns 0, stride, ... with one ``select_cols``, at stride 1 none."""
     if padding:
         window = jnp.pad(window, ((0, 0), (padding, padding), (0, 0)),
                          constant_values=NEG_INF)
-    phases = [column_phases(window[dy], stride, k) for dy in range(k)] \
-        if stride > 1 else None
-    acc = jnp.full((out_w, window.shape[-1]), NEG_INF, window.dtype)
-    for dy in range(k):
-        for dx in range(k):
-            acc = jnp.maximum(
-                acc, _tap_cols(window, dy, dx, stride, out_w, phases))
-    return acc
+    vmax = window[0]
+    for dy in range(1, k):
+        vmax = jnp.maximum(vmax, window[dy])
+    w = vmax.shape[0] - k + 1
+    hmax = vmax[:w, :]
+    for dx in range(1, k):
+        hmax = jnp.maximum(hmax, vmax[dx:dx + w, :])
+    if stride == 1:
+        return hmax
+    return select_cols(hmax, 0, stride, out_w)
 
 
 def project_row(src_row, w_t: int, c_t: int):
@@ -133,3 +146,22 @@ def project_row(src_row, w_t: int, c_t: int):
     elif c_t < c_s:
         y = y[:, :c_t]
     return y
+
+
+def column_selects(net: NetSpec, a: int, b: int) -> int:
+    """``select_cols`` calls one image costs in SPAN(a, b) of ``net`` as
+    the kernel runs it (after ``kernel.span_input_rewrite``): one per
+    output row of a strided pool, k * min(stride, k) (the column phases
+    of its window rows) per output row of a strided conv, and one per
+    output row of a shortcut that subsamples its source's columns."""
+    n = 0
+    for m in range(a + 1, b + 1):
+        layer = net.layers[m - 1]
+        if layer.stride > 1:
+            per_row = (1 if layer.kind == "pool"
+                       else layer.k * min(layer.stride, layer.k))
+            n += layer.out_h * per_row
+        h_m, w_m, _ = net.map_shape(m)
+        n += h_m * sum(1 for s, t in net.residual_edges
+                       if t == m and net.map_shape(s)[1] // w_m > 1)
+    return n

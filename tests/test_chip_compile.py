@@ -81,8 +81,9 @@ def _lower_args(net, a, b, sharding, batch=2):
     ("resnet18", 6, 8),    # stride-2 block with its residual downsample
     ("vggnet", 16, 17),    # 3x3 conv, 512 -> 512 channels
     ("alexnet", 0, 8),     # whole body: past the default scoped VMEM
+    ("vggnet", 0, 6),      # 2x2 stride-2 pools over 224- and 112-wide rows
 ], ids=["stem-s4", "pool-s2", "residual-downsample", "conv-512ch",
-        "alexnet-body-vmem"])
+        "alexnet-body-vmem", "vgg-pools-224"])
 def test_span_kernel_compiles_for_v5e(name, a, b, one_chip,
                                       no_persistent_cache):
     net = zoo.get_network(name)

@@ -41,6 +41,9 @@ SPAN_CASES = [
     pytest.param("vgg-block", [(C, 3, 1, 1, 8), (C, 3, 1, 1, 8),
                                (P, 2, 2, 0, 0), (C, 3, 1, 1, 16)], 8, 3,
                  marks=pytest.mark.slow),
+    # VGG's 2x2 stride-2 unpadded pools, over an even and an odd width
+    ("vgg-pools", [(C, 3, 1, 1, 4), (P, 2, 2, 0, 0), (C, 3, 1, 1, 8),
+                   (P, 2, 2, 0, 0)], 14, 3),
 ]
 
 
